@@ -17,6 +17,8 @@ trajectory is tracked across PRs alongside ``BENCH_sweep.json``.
 """
 from __future__ import annotations
 
+import os
+import tempfile
 import time
 
 import jax
@@ -153,6 +155,11 @@ def run(quick: bool = True) -> list[dict]:
     # run, not of two noisy runs racing each other.
     prov = ops.backend_provenance("auto")
     interpret = prov["platform"] != "tpu"
+    if autotune.cache_path() is None:
+        # tune() publishes into $REPRO_AUTOTUNE_CACHE; without one, the
+        # tuned table lives only for this run
+        tune_dir = tempfile.TemporaryDirectory()
+        os.environ["REPRO_AUTOTUNE_CACHE"] = tune_dir.name
     reps = 2 if interpret else 20
     tune_shapes = (
         [(256, 10), (128, 64), (64, 200)] if quick
@@ -208,7 +215,7 @@ def run(quick: bool = True) -> list[dict]:
     # path elsewhere — interpret-mode Pallas timings would measure the
     # interpreter, not the kernel). Peaks are host-calibrated off-TPU, and
     # the flop model follows the implementation that actually ran: the
-    # matmul-sortscan count on TPU, the jnp sort+sweep count elsewhere.
+    # in-kernel sortscan count on TPU, the jnp sort+sweep count elsewhere.
     from repro.kernels.oga_step import pack_scal
 
     model_method = "sortscan" if prov["fused_impl"] == "pallas" else "rows"
@@ -231,7 +238,7 @@ def run(quick: bool = True) -> list[dict]:
         _, us_p = timed(jit_prod, zt, at, mt, xt, kt, st, repeats=20)
         rl = roofline_mod.kernel_roofline(
             "oga_step", Nt, Lt, us_p, method=model_method,
-            platform=prov["platform"],
+            platform=prov["platform"], device_kind=prov["device_kind"],
         )
         emit(f"kernel.roofline.oga_step.N={Nt}.L={Lt}", us_p,
              f"dom={rl['dominant']};"
@@ -249,7 +256,8 @@ def run(quick: bool = True) -> list[dict]:
     jit_proj(zt, at, mt, ct).block_until_ready()
     _, us_pr = timed(jit_proj, zt, at, mt, ct, repeats=20)
     rl = roofline_mod.kernel_roofline(
-        "proj", Nt, Lt, us_pr, method=model_method, platform=prov["platform"]
+        "proj", Nt, Lt, us_pr, method=model_method,
+        platform=prov["platform"], device_kind=prov["device_kind"],
     )
     emit(f"kernel.roofline.proj.N={Nt}.L={Lt}", us_pr,
          f"dom={rl['dominant']};frac_bytes={rl['frac_peak_bytes']:.3f};"

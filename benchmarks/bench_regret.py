@@ -48,7 +48,7 @@ def run(quick: bool = True) -> list[dict]:
         # provenance the JSON needs to be interpretable on its own
         r.update(
             T=T, eta="theoretical(eq.50)", decay=1.0,
-            jit_backend_compiles=cc.count if cc.supported else None,
+            jit_backend_compiles=cc.count,
         )
         exp, lo, hi = r["exponent"], r["ci_lo"], r["ci_hi"]
         emit(

@@ -95,7 +95,7 @@ def _time_streamed(
     summ = {k: np.concatenate(v) for k, v in parts.items()}
     stall = stats.get("chunk_wait_s", 0.0)
     overlap = max(0.0, min(1.0, 1.0 - stall / max(wall, 1e-9)))
-    return wall, summ, overlap, (cc.count if cc.supported else None)
+    return wall, summ, overlap, cc.count
 
 
 def _record(name, mode, G, chunk, elapsed, records, backend="fused",

@@ -43,6 +43,10 @@ def main() -> None:
     args, _ = ap.parse_known_args()
     quick = not args.full
 
+    from repro import compat
+
+    compat.use_repo_compile_cache()
+
     from benchmarks import (
         bench_contention,
         bench_faults,

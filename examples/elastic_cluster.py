@@ -8,8 +8,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.launch.elastic import plan_mesh
 from repro.sched.job_manager import JobManager, JobTemplate, build_cluster
+
+compat.use_repo_compile_cache()
 
 jobs = [
     JobTemplate(arch="qwen2-72b", chips=4.0, hbm_gb=48.0),

@@ -3,8 +3,11 @@ trace (paper Fig. 2 in miniature), plus the regret certificate.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from repro import compat
 from repro.sched import trace
 from repro.sched.simulator import improvement_over_baselines, run_all
+
+compat.use_repo_compile_cache()
 
 cfg = trace.TraceConfig(T=800, L=10, R=64, K=6, seed=1, contention=10.0)
 results = run_all(cfg, with_regret=True)

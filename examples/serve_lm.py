@@ -6,9 +6,12 @@ import time
 
 import jax
 
+from repro import compat
 from repro.configs import base as configs
 from repro.models import model as M
 from repro.serve.engine import Engine, Request
+
+compat.use_repo_compile_cache()
 
 cfg = configs.reduced(configs.get("musicgen-medium"))
 params = M.init_params(cfg, jax.random.PRNGKey(0))

@@ -9,6 +9,7 @@ Default is a CPU-feasible ~10M config (CI-speed); ``--full-100m`` selects the
 import argparse
 import dataclasses
 
+from repro import compat
 from repro.configs import base as configs
 from repro.data.pipeline import DataConfig
 from repro.optim import AdamWConfig
@@ -19,6 +20,7 @@ ap.add_argument("--steps", type=int, default=60)
 ap.add_argument("--full-100m", action="store_true")
 ap.add_argument("--ckpt-dir", default="/tmp/repro_example_ckpt")
 args = ap.parse_args()
+compat.use_repo_compile_cache()
 
 if args.full_100m:
     # ~100M params: 51M tied-scale embeddings + 8 x 3.1M blocks + head

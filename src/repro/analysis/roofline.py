@@ -14,8 +14,14 @@ from __future__ import annotations
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s.
+TPU_PEAKS = {
+    "TPU v5 lite": {"peak_flops_s": 197e12, "peak_bytes_s": 819e9},
+}
+PEAK_FLOPS = TPU_PEAKS["TPU v5 lite"]["peak_flops_s"]   # bf16 / chip
+HBM_BW = TPU_PEAKS["TPU v5 lite"]["peak_bytes_s"]       # B/s / chip
 ICI_BW = 50e9             # B/s / link
 
 _DTYPE_BYTES = {
@@ -217,13 +223,12 @@ def dryrun_summary(record: dict) -> dict:
 
 # --------------------------------------------------------------------------
 # Measured-kernel roofline: achieved bytes/s and flops/s of the *timed*
-# scheduler kernels against a peak model. On TPU the peaks are the chip
-# datasheet constants above; on a host backend they are CALIBRATED once per
-# process — a large memcpy for bandwidth, a large f32 matmul for flops — so
-# "fraction of peak" means fraction of what this machine demonstrably
-# sustains, not of a TPU it is not. benchmarks/bench_kernels.py emits these
-# records into BENCH_kernels.json and the CI kernel-gate compares the
-# normalized fractions, which is what makes the gate machine-portable.
+# scheduler kernels against a peak model. On TPU the peaks are the published
+# per-chip constants of TPU_PEAKS, looked up by device kind; on a host backend
+# they are CALIBRATED once per process — a large memcpy for bandwidth, a
+# large f32 matmul for flops — so "fraction of peak" means fraction of what
+# this machine demonstrably sustains, not of a TPU it is not.
+# benchmarks/bench_kernels.py emits these records into BENCH_kernels.json.
 # --------------------------------------------------------------------------
 
 _kernel_peaks_cache: Optional[dict] = None
@@ -256,18 +261,23 @@ def _calibrate_host_peaks() -> dict:
     return {"peak_bytes_s": bw, "peak_flops_s": fl, "calibrated": True}
 
 
-def kernel_peaks(platform: Optional[str] = None) -> dict:
+def kernel_peaks(
+    platform: Optional[str] = None, device_kind: Optional[str] = None
+) -> dict:
     """Peak model for the measured-kernel roofline, cached per process.
 
-    TPU: datasheet constants (PEAK_FLOPS, HBM_BW). Anything else:
-    host-calibrated measured peaks (see module comment).
+    TPU: the published peaks of ``device_kind`` (TPU_PEAKS); a TPU kind not
+    in the table raises rather than borrowing another chip's peaks.
+    Anything else: host-calibrated measured peaks (see module comment).
     """
     global _kernel_peaks_cache
     if platform == "tpu":
-        return {
-            "peak_bytes_s": HBM_BW, "peak_flops_s": PEAK_FLOPS,
-            "calibrated": False,
-        }
+        if device_kind not in TPU_PEAKS:
+            raise ValueError(
+                f"no published peaks for TPU device kind {device_kind!r}; "
+                f"known kinds: {sorted(TPU_PEAKS)}"
+            )
+        return {**TPU_PEAKS[device_kind], "calibrated": False}
     if _kernel_peaks_cache is None:
         _kernel_peaks_cache = _calibrate_host_peaks()
     return _kernel_peaks_cache
@@ -289,11 +299,13 @@ def kernel_cost_model(
     Bytes count each f32 operand read once and the output written once —
     the fused kernels are single-pass by construction, so this is the
     traffic a perfect memory system would move. Flops follow the method:
-    bisect evaluates g per halving (~4 flops/lane/iter); sortscan runs its
-    bitonic/scan work as (P, P) matmuls with P = next_pow2(2 * lanes),
-    counted at 2 flops/MAC; "rows" models the off-TPU jnp packed-rows path
-    (one real sort over the 2L breakpoints + prefix-sum sweep — no
-    permutation matmuls), so off-TPU measurements are compared against the
+    bisect evaluates g per halving (~4 flops/lane/iter); sortscan runs a
+    bitonic network over P = next_pow2(2 * lanes) lanes (~10 vector ops per
+    lane per compare-exchange stage: partner selects, compares, the swap
+    mask and the two swap selects) and two log2(P)-step rotate-and-add
+    prefix sums (rotations move data and count no flops); "rows" models the
+    off-TPU jnp packed-rows path (one real sort over the 2L breakpoints +
+    prefix-sum sweep), so off-TPU measurements are compared against the
     work that implementation actually does, not the Pallas substitute.
     """
     lp = max(128, -(-l // 128) * 128)
@@ -312,9 +324,8 @@ def kernel_cost_model(
         lg = p.bit_length() - 1
         stages = lg * (lg + 1) // 2
         proj_flops = n * (
-            stages * 2 * 2 * p * p   # bitonic: 2 (P, P) matmuls per stage
-            + 3 * 2 * p * p          # prefix sums + shift matmuls
-            + 2 * 2 * lp * p         # breakpoint scatter matmuls
+            stages * 10 * p          # bitonic compare-exchange stages
+            + 2 * 2 * lg * p         # two prefix sums: add + mask per step
             + 30.0 * lp              # closed-form segment finish
         )
     elif method == "rows":
@@ -335,6 +346,7 @@ def kernel_roofline(
     method: str = "sortscan",
     iters: int = 20,
     platform: Optional[str] = None,
+    device_kind: Optional[str] = None,
     peaks: Optional[dict] = None,
 ) -> dict:
     """Measured achieved-vs-peak record for one timed kernel call.
@@ -345,7 +357,7 @@ def kernel_roofline(
     bound kernels that is virtually always bytes).
     """
     cost = kernel_cost_model(kernel, n, l, method=method, iters=iters)
-    pk = peaks or kernel_peaks(platform)
+    pk = peaks or kernel_peaks(platform, device_kind)
     t = max(us, 1e-9) * 1e-6
     achieved_b = cost["bytes"] / t
     achieved_f = cost["flops"] / t

@@ -1,36 +1,50 @@
-"""JAX API-drift shims and runtime sanitizers.
+"""JAX runtime glue: the sweep engine's device mesh, the compile cache
+location, and the runtime sanitizers.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and renamed ``check_rep`` to ``check_vma`` along the way; this wrapper accepts
-the new-style call on either version. ``set_mesh`` falls back to the Mesh
-context manager that predates it. ``grid_mesh`` builds the 1-D
-all-local-devices mesh the sharded sweep engine lays grid axes over.
+``grid_mesh`` builds the 1-D all-local-devices mesh the sharded sweep
+engine lays grid axes over. ``use_repo_compile_cache`` gives entry points
+one persistent compilation cache at a fixed path inside the checkout.
 
-The sanitizer half (``transfer_guard``, ``checking_leaks``,
-``CompilationCounter``) wraps the jax runtime facilities the test suite and
-benchmark gates use to catch the bug classes the static linter
-(``repro.analysis.lint``) checks for syntactically: implicit host<->device
-transfers inside hot paths, tracer leaks out of traced scopes, and silent
-per-call recompilation. Each wrapper degrades to a no-op on jax versions
-that lack the underlying API, so tier-1 stays green across the shim matrix.
+The sanitizer half (``CompilationCounter``) wraps the jax runtime facility
+the test suite and benchmark gates use to catch silent per-call
+recompilation, the runtime face of the static linter's
+(``repro.analysis.lint``) jit rules; tests run the transfer and leak
+guards (``jax.transfer_guard``, ``jax.checking_leaks``) directly.
 """
 from __future__ import annotations
 
-import contextlib
+import os
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
+# <repo>/.jax_cache: a fixed path, so the next run of the same checkout finds
+# what this one compiled
+REPO_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def use_repo_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache in ``<repo>/.jax_cache``,
+    unless ``JAX_COMPILATION_CACHE_DIR`` names one (JAX reads that itself).
+    Returns the directory in use. Entry points call this before they
+    compile; library code never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", REPO_COMPILE_CACHE)
+    return REPO_COMPILE_CACHE
+
 
 def grid_mesh(axis: str = "grid", devices: Optional[Sequence] = None) -> Optional[Mesh]:
     """1-D mesh over all local devices, or None on a single-device host.
 
     The None return is the signal consumers (sweep.run_grid_sharded) use to
-    fall back to the plain single-device vmap path; constructed directly via
-    ``Mesh`` because ``jax.make_mesh`` does not take an explicit device list
-    on every supported jax version.
+    fall back to the plain single-device vmap path.
     """
     devs = list(jax.devices()) if devices is None else list(devices)
     if len(devs) <= 1:
@@ -38,58 +52,7 @@ def grid_mesh(axis: str = "grid", devices: Optional[Sequence] = None) -> Optiona
     return Mesh(np.asarray(devs), (axis,))
 
 
-def set_mesh(mesh):
-    """``jax.set_mesh(mesh)`` on new jax, ``with mesh:`` on old."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-_native = getattr(jax, "shard_map", None)
-if _native is None:
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    kw = {}
-    if _native is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return _native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _experimental_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
-
-
 # ----------------------------------------------------- runtime sanitizers --
-
-
-def transfer_guard(policy: str = "disallow"):
-    """``jax.transfer_guard(policy)``, or a null context on old jax.
-
-    Under ``"disallow"`` jax raises on *implicit* host<->device transfers
-    (a numpy array silently fed to a jitted function, ``float()`` on a
-    device array) while explicit ``jax.device_put`` / ``jnp.asarray`` /
-    ``jax.device_get`` stay allowed — exactly the line the
-    ``host-sync-in-hot-loop`` lint rule draws syntactically.
-    """
-    tg = getattr(jax, "transfer_guard", None)
-    if tg is None:
-        return contextlib.nullcontext()
-    return tg(policy)
-
-
-def checking_leaks():
-    """``jax.checking_leaks()``, or a null context on old jax.
-
-    Errors when a tracer escapes its trace — the runtime face of the
-    ``impure-scan-body`` lint rule.
-    """
-    cl = getattr(jax, "checking_leaks", None)
-    if cl is None:
-        return contextlib.nullcontext()
-    return cl()
 
 
 # jax.monitoring has no unregister API, so a single process-wide listener is
@@ -108,18 +71,11 @@ def _on_compile_event(event: str, duration: float, **kwargs) -> None:
         _compile_events += 1
 
 
-def _install_compile_listener() -> bool:
+def _install_compile_listener() -> None:
     global _listener_installed
-    if _listener_installed:
-        return True
-    try:
-        from jax import monitoring
-
-        monitoring.register_event_duration_secs_listener(_on_compile_event)
-    except Exception:
-        return False
-    _listener_installed = True
-    return True
+    if not _listener_installed:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+        _listener_installed = True
 
 
 def backend_compile_count() -> int:
@@ -134,16 +90,12 @@ class CompilationCounter:
     >>> with CompilationCounter() as c:
     ...     f(x)          # warm call
     >>> c.count           # 0 if f hit the jit cache, >=1 if it recompiled
-
-    ``supported`` is False when jax.monitoring is unavailable; callers
-    gating CI on ``count`` should skip (not pass) in that case.
     """
 
     count: int = 0
-    supported: bool = False
 
     def __enter__(self) -> "CompilationCounter":
-        self.supported = _install_compile_listener()
+        _install_compile_listener()
         self._start = _compile_events
         self.count = 0
         return self

@@ -149,7 +149,9 @@ def _default_w(spec: ClusterSpec, name: str) -> jax.Array:
 def drf_step(spec: ClusterSpec, x: jax.Array, w=None) -> jax.Array:
     """DRF: ascending dominant share s_l = max_k a_l^k / sum_{r in R_l} c_r^k."""
     w = _default_w(spec, "drf") if w is None else w
-    cap_l = jnp.einsum("lr,rk->lk", spec.mask, spec.c)  # (L, K) reachable cap
+    # (L, K) reachable capacity; HIGHEST keeps the TPU from summing c as bf16
+    cap_l = jnp.einsum("lr,rk->lk", spec.mask, spec.c,
+                       precision=jax.lax.Precision.HIGHEST)
     s = jnp.max(spec.a / jnp.maximum(cap_l, 1e-9), axis=1)  # (L,)
     s = jnp.where(x > 0, s, _BIG)  # arrived ports first
     order = _rank_order(s)

@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 from repro.core import projection, utilities
 from repro.core.graph import ClusterSpec
 
@@ -68,7 +66,7 @@ def make_distributed_step(spec: ClusterSpec, mesh: Mesh, axis: str = "data"):
     )
 
     @partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec_shardings, pspec_y, P(None), P()),
         out_specs=(pspec_y, P()),

@@ -62,6 +62,7 @@ def backend_provenance(backend: str = "auto") -> dict:
         "backend_requested": backend,
         "backend_resolved": resolved,
         "platform": _platform(),
+        "device_kind": jax.devices()[0].device_kind,
         "fused_impl": fused_impl if resolved == "fused" else "spec-level",
     }
 

@@ -13,8 +13,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.models.layers import he_init
 from repro.train.meshctx import constrain
 
@@ -178,7 +176,7 @@ def apply_moe_ep(p, x, cfg, mesh):
         p_specs["shared"] = {k: P(None, None) for k in p["shared"]}
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(p_specs, x_spec),
         out_specs=x_spec,
@@ -233,7 +231,7 @@ def apply_mlp_ep(p, x, cfg, mesh):
                "down": P("model", None)}
 
     @functools.partial(
-        compat.shard_map, mesh=mesh, in_specs=(p_specs, x_spec),
+        jax.shard_map, mesh=mesh, in_specs=(p_specs, x_spec),
         out_specs=x_spec, check_vma=False,
     )
     def f(p_local, x_local):

@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tf
 
@@ -64,7 +62,7 @@ def pipeline_forward(
     p_specs = jax.tree.map(lambda _: P(axis), staged)
 
     @functools.partial(
-        compat.shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(p_specs, P(axis), P(None), P(None)),
         out_specs=P(None),

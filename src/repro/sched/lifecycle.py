@@ -110,6 +110,8 @@ class LifecycleState:
     svc_work:  (L,) total work of the in-service job (restart/wasted-work
                anchor under evictions).
     svc_retry: (L,) evictions the in-service job has survived so far.
+    svc_rate:  (L,) service rate of the in-service job's held allocation,
+               fixed at admission (held does not change during a tenure).
     q_work:    (L, Q) FIFO of queued job sizes (0-padded past q_len).
     q_arr:     (L, Q) FIFO of queued arrival slots.
     q_ready:   (L, Q) FIFO of earliest-admission slots (backoff gates).
@@ -129,6 +131,7 @@ class LifecycleState:
     svc_start: jax.Array
     svc_work: jax.Array
     svc_retry: jax.Array
+    svc_rate: jax.Array
     q_work: jax.Array
     q_arr: jax.Array
     q_ready: jax.Array
@@ -195,6 +198,7 @@ def init_state(
         svc_start=jnp.zeros((L,), jnp.int32),
         svc_work=jnp.zeros((L,), dtype),
         svc_retry=jnp.zeros((L,), jnp.int32),
+        svc_rate=jnp.zeros((L,), dtype),
         q_work=jnp.zeros((L, queue_depth), dtype),
         q_arr=jnp.zeros((L, queue_depth), jnp.int32),
         q_ready=jnp.zeros((L, queue_depth), jnp.int32),
@@ -247,9 +251,13 @@ def _evict(
            & (idx[None, :] <= idx[:, None]))
     )  # (L, L): job j at or before job l in the keep order
     held_m = state.held * spec.mask[:, :, None]
+    # (L, R, K) cumulative usage of the rank-<=l prefix. HIGHEST: at the
+    # TPU's default matmul precision the held f32 values would be summed as
+    # bf16, an error far past FEAS_TOL that evicts jobs no fault displaced.
     cum = jnp.einsum(
-        "lj,jrk->lrk", before_eq.astype(dtype), held_m
-    )  # (L, R, K) cumulative usage of the rank-<=l prefix
+        "lj,jrk->lrk", before_eq.astype(dtype), held_m,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     slack = FEAS_TOL * (1.0 + c_t)
     fits = jnp.all(cum <= (c_t + slack)[None], axis=(1, 2))
     evict = in_svc & ~fits
@@ -393,6 +401,7 @@ def _step(
         reward_t = reward.total_reward(
             spec, admit_f, held * admit_f[:, None, None]
         )
+        svc_rate = reward.service_rates(spec, held)
     else:
         # Heuristics and OGA hold allocations for a job's whole tenure:
         # allocate the admitted jobs against the *surviving residual*
@@ -409,8 +418,12 @@ def _step(
         alloc = projection.project_sorted(
             y_prop * admit_f[:, None, None], spec.a, c_res, spec.mask
         )
-        reward_t = reward.total_reward(spec, admit_f, alloc)
+        # a held allocation is fixed for the job's tenure, and so is its
+        # service rate: compute it once, at admission, and carry it
+        rate_alloc = reward.service_rates(spec, alloc)
+        reward_t = jnp.sum(admit_f * rate_alloc)
         held = jnp.where(admit[:, None, None], alloc, state.held)
+        svc_rate = jnp.where(admit, rate_alloc, state.svc_rate)
     remaining = jnp.where(admit, new_work, state.remaining)
     svc_arr = jnp.where(admit, new_arr, state.svc_arr)
     svc_start = jnp.where(admit, t, state.svc_start)
@@ -421,7 +434,7 @@ def _step(
     # -- service: drain work at the utility-derived rate of the held alloc --
     in_svc = remaining > 0
     in_svc_f = in_svc.astype(dtype)
-    rates = jnp.maximum(reward.service_rates(spec, held), rate_floor)
+    rates = jnp.maximum(svc_rate, rate_floor)
     rem2 = remaining - rates * in_svc_f
     work_done = jnp.minimum(rates, remaining) * in_svc_f
     depart = in_svc & (rem2 <= 0)
@@ -445,7 +458,7 @@ def _step(
 
     new_state = LifecycleState(
         held=held, remaining=remaining, svc_arr=svc_arr, svc_start=svc_start,
-        svc_work=svc_work, svc_retry=svc_retry,
+        svc_work=svc_work, svc_retry=svc_retry, svc_rate=svc_rate,
         q_work=q_work, q_arr=q_arr, q_ready=q_ready, q_retry=q_retry,
         q_len=q_len, dropped=dropped, rdropped=state.rdropped,
         y=y_next, eta=state.eta * decay, t=t + 1,

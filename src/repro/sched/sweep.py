@@ -504,7 +504,7 @@ def _sharded_grid_fn(
                 tiling=tiling,
             )
         in_specs = (gspec, gspec, gspec, gspec)
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=gspec, check_vma=False,
     ))
 

@@ -26,15 +26,14 @@ def _runtime_sanitizers(request):
 
     transfer_guard("disallow") turns any *implicit* host<->device transfer
     into an error (explicit device_put/jnp.asarray/device_get stay legal);
-    checking_leaks errors on tracers escaping their trace. Both degrade to
-    no-ops on jax versions lacking the APIs (see repro.compat).
+    checking_leaks errors on tracers escaping their trace.
     """
     if request.node.get_closest_marker("sanitized") is None:
         yield
         return
-    from repro import compat
+    import jax
 
-    with compat.transfer_guard("disallow"), compat.checking_leaks():
+    with jax.transfer_guard("disallow"), jax.checking_leaks():
         yield
 
 

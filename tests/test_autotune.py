@@ -225,3 +225,20 @@ def test_kernel_config_is_hashable_jit_static():
     cfg = autotune.KernelConfig(32, "sortscan", 0)
     assert hash(cfg) == hash(autotune.KernelConfig(32, "sortscan", 0))
     assert cfg.to_dict() == {"row_block": 32, "method": "sortscan", "iters": 0}
+
+
+def test_unset_cache_env_reads_no_file_and_refuses_to_publish(monkeypatch):
+    """Without REPRO_AUTOTUNE_CACHE dispatch runs the committed default and
+    opens no table (none in the home directory either); tune() cannot
+    publish a winner nobody would read."""
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE")
+    autotune.reset_cache()
+    assert autotune.cache_path() is None
+    opened = []
+    with monkeypatch.context() as m:
+        m.setattr("builtins.open", lambda *a, **k: opened.append(a))
+        assert autotune.resolve("oga_step", 4096, 10) == autotune.DEFAULT_CONFIG
+    assert opened == []
+    table = {(rb, "sortscan", 0): float(rb) for rb in autotune.ROW_BLOCKS}
+    with pytest.raises(ValueError, match="REPRO_AUTOTUNE_CACHE"):
+        autotune.tune("proj", 64, 10, measure=_fake_measure(table))

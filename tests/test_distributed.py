@@ -19,7 +19,6 @@ def test_distributed_step_matches_single_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro import compat
         from repro.core import distributed, ogasched, reward, projection
         from repro.sched import trace
 
@@ -36,7 +35,7 @@ def test_distributed_step_matches_single_device():
         x = (jax.random.uniform(jax.random.PRNGKey(1), (6,)) < 0.7).astype(jnp.float32)
         eta = jnp.asarray(3.0)
 
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_next_d, q_d = step(sspec, y, x, eta)
         # single-device reference
         q_ref = reward.total_reward(spec, x, y)

@@ -3,8 +3,11 @@ with 8 host devices), values bit-identical; plan_mesh power-of-two logic."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 from repro.launch.elastic import plan_mesh
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 def test_plan_mesh_power_of_two():
@@ -42,6 +45,6 @@ def test_reshard_across_meshes():
         [sys.executable, "-c", script],
         capture_output=True, text=True,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=600,
+        cwd=REPO, timeout=600,
     )
     assert "ELASTIC-OK" in res.stdout, res.stdout + res.stderr

@@ -6,6 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:  # dependency-free fallback (see _hypothesis_compat)
@@ -138,36 +140,48 @@ def test_proj_sortscan_property_feasibility(seed):
     assert (y.sum(1) <= np.asarray(c) + 1e-5).all()
 
 
+def _run_in_kernel(fn, n_out, *xs):
+    """``fn(*xs, lane_iota)`` on whole arrays inside an interpret-mode Pallas
+    kernel: the lane rotations it uses lower only there."""
+    def kernel(*refs):
+        ins, outs = refs[:len(xs)], refs[len(xs):]
+        idx = jax.lax.broadcasted_iota(jnp.int32, xs[0].shape, 1)
+        res = fn(*(r[...] for r in ins), idx)
+        for o, r in zip(outs, res if isinstance(res, tuple) else (res,)):
+            o[...] = r
+
+    shape = jax.ShapeDtypeStruct(xs[0].shape, jnp.float32)
+    out = pl.pallas_call(kernel, out_shape=[shape] * n_out, interpret=True)(*xs)
+    return [np.asarray(o) for o in out]
+
+
 def test_bitonic_sort_pairs_unit():
-    """The matmul-only bitonic network sorts ascending with the payload
+    """The rotation-only bitonic network sorts ascending with the payload
     riding its value exactly (distinct keys)."""
     rng = np.random.default_rng(0)
     v = rng.normal(size=(3, 16)).astype(np.float32)
     d = rng.normal(size=(3, 16)).astype(np.float32)
-    vs, ds = sortscan._bitonic_sort_pairs(jnp.asarray(v), jnp.asarray(d))
+    vs, ds = _run_in_kernel(
+        sortscan._bitonic_sort_pairs, 2, jnp.asarray(v), jnp.asarray(d)
+    )
     order = np.argsort(v, axis=1)
-    np.testing.assert_array_equal(np.asarray(vs), np.take_along_axis(v, order, 1))
-    np.testing.assert_array_equal(np.asarray(ds), np.take_along_axis(d, order, 1))
+    np.testing.assert_array_equal(vs, np.take_along_axis(v, order, 1))
+    np.testing.assert_array_equal(ds, np.take_along_axis(d, order, 1))
 
 
 def test_scan_matmul_helpers_unit():
-    """Cumsum / shift / XOR-partner as constant 0-1 matmuls (the Mosaic-safe
-    substitutes for scan, roll, and gather)."""
+    """Cumsum, shift-by-one and lane rotation (the Mosaic-safe substitutes
+    for scan, shift and gather) on a known row."""
     x = jnp.asarray([[1.0, 2.0, 3.0, 4.0]])
-    np.testing.assert_array_equal(
-        np.asarray(sortscan._dot(x, sortscan._tri_mat(4))), [[1.0, 3.0, 6.0, 10.0]]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(sortscan._dot(x, sortscan._shift_mat(4))), [[0.0, 1.0, 2.0, 3.0]]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(sortscan._dot(x, sortscan._partner_mat(4, 1))),
-        [[2.0, 1.0, 4.0, 3.0]],
-    )
-    np.testing.assert_array_equal(
-        np.asarray(sortscan._dot(x, sortscan._partner_mat(4, 2))),
-        [[3.0, 4.0, 1.0, 2.0]],
-    )
+    cases = [
+        (sortscan._cumsum, [[1.0, 3.0, 6.0, 10.0]]),
+        (sortscan._shift1, [[0.0, 1.0, 2.0, 3.0]]),
+        (lambda v, idx: sortscan._roll(v, 1), [[4.0, 1.0, 2.0, 3.0]]),
+        (lambda v, idx: sortscan._roll(v, -1), [[2.0, 3.0, 4.0, 1.0]]),
+    ]
+    for fn, want in cases:
+        (got,) = _run_in_kernel(fn, 1, x)
+        np.testing.assert_array_equal(got, want)
 
 
 def test_ops_proj_sortscan_dispatcher_paths():

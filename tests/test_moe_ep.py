@@ -3,6 +3,9 @@ needs 8 host devices before jax initialises)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 def test_moe_ep_matches_reference():
@@ -21,7 +24,8 @@ def test_moe_ep_matches_reference():
                          capacity_factor=8.0, param_dtype="float32",
                          compute_dtype="float32")
         p = moe_lib.init_moe(jax.random.PRNGKey(0), 32, 16, 8, 1, jnp.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
         # full-seq path (all_gather + psum_scatter)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
@@ -46,6 +50,6 @@ def test_moe_ep_matches_reference():
         [sys.executable, "-c", script],
         capture_output=True, text=True,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=600,
+        cwd=REPO, timeout=600,
     )
     assert "MOE-EP-OK" in res.stdout, res.stdout + res.stderr

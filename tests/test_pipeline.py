@@ -3,6 +3,9 @@ pipeline on 4 host devices), gradients included."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent.parent
 
 
 def test_pipeline_matches_reference_and_grads():
@@ -16,7 +19,8 @@ def test_pipeline_matches_reference_and_grads():
 
         cfg = configs.reduced(configs.get("stablelm-3b"), n_layers=8)
         params = M.init_params(cfg, jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         B, S = 4, 16
         x = jax.random.normal(jax.random.PRNGKey(1), (B, S, cfg.d_model))
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
@@ -42,6 +46,6 @@ def test_pipeline_matches_reference_and_grads():
         [sys.executable, "-c", script],
         capture_output=True, text=True,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=900,
+        cwd=REPO, timeout=900,
     )
     assert "PIPELINE-OK" in res.stdout, res.stdout + res.stderr[-3000:]

@@ -106,8 +106,6 @@ def test_compilation_counter_counts_fresh_compiles():
     x = jnp.arange(13, dtype=jnp.float32)
     with compat.CompilationCounter() as c1:
         jax.block_until_ready(f(x))
-    if not c1.supported:
-        pytest.skip("jax.monitoring compile events unavailable")
     with compat.CompilationCounter() as c2:
         jax.block_until_ready(f(x))
     assert c1.count >= 1  # cold call really compiled
@@ -131,8 +129,6 @@ def test_sweep_stream_compiles_once_per_chunk_shape(compile_counter):
     with compile_counter() as c:
         for _, _, out in it:
             jax.block_until_ready(out)
-    if not c.supported:
-        pytest.skip("jax.monitoring compile events unavailable")
     assert c.count == 0
 
 
@@ -143,8 +139,6 @@ def test_sweep_stream_warm_rerun_compiles_nothing(compile_counter):
     _drain(pts, **kw)  # warm
     with compile_counter() as c:
         _drain(pts, **kw)
-    if not c.supported:
-        pytest.skip("jax.monitoring compile events unavailable")
     assert c.count == 0
 
 
@@ -155,7 +149,5 @@ def test_regret_stream_compiles_once_per_chunk_shape(compile_counter):
     regret.regret_stream(pts, **kw)  # warm: compiles for the (2, T) chunk
     with compile_counter() as c:
         out = regret.regret_stream(pts, **kw)
-    if not c.supported:
-        pytest.skip("jax.monitoring compile events unavailable")
     assert c.count == 0
     assert out["curves"].shape == (4, out["ts"].size)

@@ -45,6 +45,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import projection, reward
 from repro.core.graph import ClusterSpec
 
@@ -371,14 +372,16 @@ def run(
 
         def body(_, xs):
             x, wk = xs
-            y = step(spec, x, w, sizes=wk)
+            with obs.scope(f"heuristic.{name}"):
+                y = step(spec, x, w, sizes=wk)
             return None, reward.total_reward(spec, x, y)
 
         _, rewards = jax.lax.scan(body, None, (arrivals, works))
     else:
 
         def body(_, x):
-            y = step(spec, x, w)
+            with obs.scope(f"heuristic.{name}"):
+                y = step(spec, x, w)
             return None, reward.total_reward(spec, x, y)
 
         _, rewards = jax.lax.scan(body, None, arrivals)

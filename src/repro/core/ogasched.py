@@ -8,6 +8,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import reward
 from repro.core.graph import ClusterSpec, random_feasible_decision
 from repro.kernels import ops
@@ -49,9 +50,10 @@ def oga_step(
     Returns (next_state, reward_at_t).
     """
     q_t = reward.total_reward(spec, x, state.y)
-    y_next = ops.oga_update_spec(
-        spec, state.y, x, state.eta, backend=backend, operands=operands,
-    )
+    with obs.scope("oga.update"):
+        y_next = ops.oga_update_spec(
+            spec, state.y, x, state.eta, backend=backend, operands=operands,
+        )
     new = OGAState(y=y_next, eta=state.eta * decay, t=state.t + 1)
     return new, q_t
 
@@ -135,10 +137,11 @@ def run_batch(
     def body(carry, x_t):
         y, eta = carry
         q_t = jax.vmap(reward.total_reward)(spec, x_t, y)
-        y_next = ops.oga_update_batch(
-            spec, y, x_t, eta, operands=operands, use_pallas=use_pallas,
-            tiling=tiling,
-        )
+        with obs.scope("oga.update"):
+            y_next = ops.oga_update_batch(
+                spec, y, x_t, eta, operands=operands, use_pallas=use_pallas,
+                tiling=tiling,
+            )
         return (y_next, eta * decay), q_t
 
     (y_final, _), qs = jax.lax.scan(

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import utilities
 from repro.core.graph import ClusterSpec
 
@@ -40,7 +41,8 @@ def port_rewards(spec: ClusterSpec, x: jax.Array, y: jax.Array) -> jax.Array:
 
 def total_reward(spec: ClusterSpec, x: jax.Array, y: jax.Array) -> jax.Array:
     """q(x, y) = sum_l q_l (eq. 8)."""
-    return jnp.sum(port_rewards(spec, x, y))
+    with obs.scope("reward"):
+        return jnp.sum(port_rewards(spec, x, y))
 
 
 def decompose(spec: ClusterSpec, x: jax.Array, y: jax.Array):

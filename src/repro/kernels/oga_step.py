@@ -146,5 +146,6 @@ def oga_step_fused(
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Np, Lp), y.dtype),
         interpret=interpret,
+        name="oga_step_fused",
     )(yp, ap, mp, xp, kp, sp)
     return out[:N, :L]

@@ -120,5 +120,6 @@ def proj_bisect(
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Np, Lp), z.dtype),
         interpret=interpret,
+        name="proj_bisect",
     )(zp, ap, mp, cp)
     return out[:N, :L]

@@ -184,5 +184,6 @@ def proj_sortscan(z, a, mask, c, *, row_block=None, interpret: bool = False):
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((Np, Lp), z.dtype),
         interpret=interpret,
+        name="proj_sortscan",
     )(zp, ap, mp, cp)
     return out[:N, :L]

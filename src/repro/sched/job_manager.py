@@ -14,6 +14,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import ogasched
 from repro.core.graph import ClusterSpec
 from repro.sched import trace
@@ -96,15 +97,20 @@ class JobManager:
 
     def step(self, arrivals: jnp.ndarray) -> dict[str, int]:
         """One slot: returns integral chips granted per arrived job."""
-        self.state, _ = ogasched.oga_step(
-            self.spec, self.state, arrivals, self.decay
-        )
-        y = np.asarray(self.state.y)  # (L, R, K)
-        chips = y[:, :, 0].sum(axis=1)  # total chips across hosts
-        grants = {}
-        for l, job in enumerate(self.jobs):
-            if float(arrivals[l]) > 0:
-                # round to power-of-two data-axis sizes (mesh-sliceable)
-                g = int(chips[l])
-                grants[job.arch] = 1 << max(g.bit_length() - 1, 0) if g > 0 else 0
+        with obs.span("online.dispatch"):
+            self.state, _ = ogasched.oga_step(
+                self.spec, self.state, arrivals, self.decay
+            )
+        with obs.span("online.to_host"):
+            y = np.asarray(self.state.y)  # (L, R, K)
+        with obs.span("online.grants"):
+            chips = y[:, :, 0].sum(axis=1)  # total chips across hosts
+            grants = {}
+            for l, job in enumerate(self.jobs):
+                if float(arrivals[l]) > 0:
+                    # round to power-of-two data-axis sizes (mesh-sliceable)
+                    g = int(chips[l])
+                    grants[job.arch] = (
+                        1 << max(g.bit_length() - 1, 0) if g > 0 else 0
+                    )
         return grants

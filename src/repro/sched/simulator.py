@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import baselines, graph, ogasched, regret
 from repro.sched import lifecycle, sweep, trace
 
@@ -59,12 +60,13 @@ def run_all(
     # reuse the sweep engine's gate: active fault configs in slot mode are
     # a config error, not something to silently ignore
     has_faults = sweep.needs_faults([sweep.SweepPoint(cfg=cfg)], mode)
-    spec, arrivals = trace.make(cfg)
-    works = (
-        trace.build_works(cfg)
-        if sweep.needs_works(algorithms, mode) else None
-    )
-    faults = trace.build_faults(cfg) if has_faults else None
+    with obs.span("run_all.synthesis"):
+        spec, arrivals = trace.make(cfg)
+        works = (
+            trace.build_works(cfg)
+            if sweep.needs_works(algorithms, mode) else None
+        )
+        faults = trace.build_faults(cfg) if has_faults else None
     out: dict[str, SimResult] = {}
     y_star = None
     # The oracle only feeds OGASCHED's regret certificate — skip the
@@ -91,11 +93,14 @@ def run_all(
             )
             metrics = {k: float(v[0]) for k, v in batched.items()}
         else:
-            rewards = sweep.run_algorithm(
-                spec, arrivals, name, eta0=eta0, decay=decay, backend=backend,
-                works=works if name in baselines.SIZE_AWARE else None,
-            )
-            rewards = np.asarray(jax.block_until_ready(rewards))
+            with obs.span(f"run_all.{name}"):
+                rewards = sweep.run_algorithm(
+                    spec, arrivals, name, eta0=eta0, decay=decay,
+                    backend=backend,
+                    works=works if name in baselines.SIZE_AWARE else None,
+                )
+                with obs.span("run_all.wait"):
+                    rewards = np.asarray(jax.block_until_ready(rewards))
         res = SimResult(
             name=name,
             rewards=rewards,

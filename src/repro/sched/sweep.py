@@ -74,7 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.ckpt import checkpoint as ckpt_io
 from repro.ckpt.manager import CheckpointManager
 from repro.core import baselines, ogasched
@@ -761,10 +761,11 @@ def _chunk_batches(
     """Synchronous chunk generation — the prefetch worker's body."""
     for start in range(start_chunk * chunk_size, len(points), chunk_size):
         chunk = list(points[start:start + chunk_size])
-        batch = build_batch(
-            chunk, mode=mode, trace_backend=trace_backend,
-            with_works=with_works,
-        )
+        with obs.span("sweep.synthesis"):
+            batch = build_batch(
+                chunk, mode=mode, trace_backend=trace_backend,
+                with_works=with_works,
+            )
         pad = chunk_size - len(chunk)
         if pad:
             batch = SweepBatch(
@@ -979,7 +980,8 @@ def run_grid_stream(
     )
     while True:
         t_wait = time.monotonic()
-        item = next(it, None)
+        with obs.span("sweep.wait"):
+            item = next(it, None)
         if stats is not None:
             stats["chunk_wait_s"] = (
                 stats.get("chunk_wait_s", 0.0) + time.monotonic() - t_wait
@@ -987,10 +989,11 @@ def run_grid_stream(
         if item is None:
             return
         sl, batch = item
-        out = runner(
-            batch, algorithms, backend=backend, mode=mode,
-            queue_depth=queue_depth, rate_floor=rate_floor, **kw,
-        )
+        with obs.span("sweep.dispatch"):
+            out = runner(
+                batch, algorithms, backend=backend, mode=mode,
+                queue_depth=queue_depth, rate_floor=rate_floor, **kw,
+            )
         g = sl.stop - sl.start
         trim = g < batch.size
         if trim:
@@ -1152,14 +1155,16 @@ def summarize(rewards: dict[str, jax.Array]) -> dict[str, np.ndarray]:
     Returns {"avg/<name>": (G,), "improvement_pct/<name>": (G,)} mirroring
     ``simulator.improvement_over_baselines`` per grid row.
     """
-    out = {f"avg/{n}": np.asarray(r).mean(axis=1) for n, r in rewards.items()}
-    if "ogasched" in rewards:
-        oga = out["avg/ogasched"]
-        for n in rewards:
-            if n != "ogasched":
-                out[f"improvement_pct/{n}"] = improvement_pct(
-                    oga, out[f"avg/{n}"]
-                )
+    with obs.span("sweep.summarize"):
+        out = {f"avg/{n}": np.asarray(r).mean(axis=1)
+               for n, r in rewards.items()}
+        if "ogasched" in rewards:
+            oga = out["avg/ogasched"]
+            for n in rewards:
+                if n != "ogasched":
+                    out[f"improvement_pct/{n}"] = improvement_pct(
+                        oga, out[f"avg/{n}"]
+                    )
     return out
 
 

@@ -11,13 +11,14 @@ only one process at a time may load the TPU compiler's library, and every
 test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import autotune, oga_step, sortscan
+from repro.kernels import autotune, oga_step, proj_bisect, sortscan
 
 # Tab. 2 deployment (R=128, K=6) over a 64-point sweep chunk: G*R*K rows
 TAB2_ROWS, TAB2_L = 64 * 128 * 6, 10
@@ -60,3 +61,30 @@ def test_proj_sortscan_wide_compiles_for_v5e(one_chip):
     _assert_mosaic(
         lambda *o: sortscan.proj_sortscan(*o, row_block=ROW_BLOCK), args
     )
+
+
+# each kernel by the name its pallas_call gives it: its function, unjitted,
+# and the widths of its operands
+KERNELS = {
+    "oga_step_fused": (oga_step.oga_step_fused,
+                       [TAB2_L] * 5 + [oga_step.NUM_SCAL]),
+    "proj_sortscan": (sortscan.proj_sortscan, [WIDE_L] * 3 + [0]),
+    "proj_bisect": (proj_bisect.proj_bisect, [WIDE_L] * 3 + [0]),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_op_keeps_its_name_under_any_caller(one_chip, kernel):
+    """The TPU op of a kernel is named by its pallas_call's ``name``, not
+    by the function that calls it: the name a trace reader looks for."""
+    fn, widths = KERNELS[kernel]
+
+    def renamed_caller(*o):
+        return fn.__wrapped__(*o, row_block=ROW_BLOCK)
+
+    rows = 768 if kernel == "oga_step_fused" else WIDE_ROWS
+    text = jax.jit(renamed_caller).lower(
+        *_shapes(one_chip, rows, *widths)).compile().as_text()
+    ops = re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"
+                     r'custom_call_target="tpu_custom_call"', text)
+    assert ops and all(re.fullmatch(rf"{kernel}(\.\d+)?", op) for op in ops)

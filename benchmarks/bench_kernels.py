@@ -165,7 +165,9 @@ def run(quick: bool = True) -> list[dict]:
         [(256, 10), (128, 64), (64, 200)] if quick
         else [(1024, 16), (512, 64), (128, 256)]
     )
-    hand_key = f"rb{autotune.DEFAULT_ROW_BLOCK}-sortscan"
+    # the hand-picked tile the kernels once hardcoded: the smallest legal
+    # row block
+    hand_key = f"rb{autotune.ROW_BLOCKS[0]}-sortscan"
     for Nt, Lt in tune_shapes:
         win, measured = autotune.tune("oga_step", Nt, Lt, repeats=reps)
         win_us = min(measured.values())

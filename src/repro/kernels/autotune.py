@@ -12,8 +12,9 @@ Design contract, in dispatch order:
 * ``resolve(kernel, n, l)`` is the ONLY entry the hot path calls. It is
   pure Python over static shapes (safe at jit trace time), consults the
   in-memory view of the on-disk table under ``$REPRO_AUTOTUNE_CACHE``, and
-  falls back to the default config on a miss or when that variable is
-  unset (then no file is read). It NEVER measures —
+  on a miss, or when that variable is unset (then no file is read),
+  answers with ``shape_rule(n, l)``: the widest row block the packed
+  shape allows. It NEVER measures —
   ``tests/test_autotune.py`` pins the warmed sweep path at zero
   measurements, and the CI ``kernel-gate`` fails on cache misses in the
   warmed bench path.
@@ -47,8 +48,8 @@ from repro import ckpt
 
 LANE_FLOOR = 128          # TPU vector lane width: last dim pads to this
 SUBLANE_FLOOR = 8         # f32 sublane granularity: row blocks are multiples
-ROW_BLOCKS = (8, 16, 32, 64, 128)   # legal row-block candidates
-DEFAULT_ROW_BLOCK = 8     # the PR 4 hand-picked tiling (autotune baseline)
+# legal row-block candidates; on a v5e no shape ran fastest at 512 (PERF.md)
+ROW_BLOCKS = (8, 16, 32, 64, 128, 256)
 BISECT_ITERS = (12, 20, 28)         # bisect-fallback iteration candidates
 DEFAULT_BISECT_ITERS = 20
 PROJ_METHODS = ("sortscan", "bisect")
@@ -58,9 +59,22 @@ SCAL_LANES = LANE_FLOOR   # packed-scalar operand rides one lane block
 # these rather than spelling its own
 FLASH_BLOCK_Q = 128
 FLASH_BLOCK_K = 128
-# VMEM budget the candidate filter assumes per core (bytes); a sortscan
-# candidate whose working set exceeds it is not enumerated
-VMEM_BUDGET = 8 * 1024 * 1024
+# the v5e's default scoped VMEM limit, as its compiler reports it ("limit
+# 16.00M"); a tile whose sortscan footprint (``vmem_bytes``) exceeds it is
+# neither enumerated nor chosen by the shape rule
+VMEM_BUDGET = 16 * 1024 * 1024
+# the fused sortscan step's scoped VMEM per row and sort lane, and per
+# squared sort lane: the v5e compiler's own accounting bounded by these at
+# every measured (row block, lanes) point, 8-512 rows x 128-1536 lanes
+VMEM_BYTES_PER_ROW_LANE = 48
+VMEM_LANE_SQ_DIVISOR = 8
+# row block x sort lanes at which the fused sortscan step ran fastest on a
+# v5e: 64 vregs per sort temporary. Every shape swept won there (256 rows
+# at 128 lanes, 128 at 256) and lost at twice it (PERF.md)
+SORT_TILE_MAX = 64 * 1024
+# fewest grid steps at which a wider tile still won on the v5e (3,072 rows
+# at 256); fewer were not measured
+MIN_GRID_STEPS = 12
 
 TABLE_VERSION = 1
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
@@ -69,7 +83,7 @@ _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 class KernelConfig(NamedTuple):
     """One tiling point: hashable, so it can ride as a jit static arg."""
 
-    row_block: int = DEFAULT_ROW_BLOCK
+    row_block: int
     method: str = DEFAULT_PROJ_METHOD
     iters: int = DEFAULT_BISECT_ITERS
 
@@ -78,12 +92,12 @@ class KernelConfig(NamedTuple):
                 "iters": self.iters}
 
 
-DEFAULT_CONFIG = KernelConfig()
-
-# process-local state: in-memory table view + hit/miss/measurement counters
+# process-local state: in-memory table view, hit/miss/measurement/rule
+# counters, and the last (row_block, rows, padded lanes) resolved
 _table: Optional[dict] = None
 _table_path: Optional[str] = None
-_stats = {"hits": 0, "misses": 0, "measurements": 0}
+_stats: dict = {"hits": 0, "misses": 0, "measurements": 0, "rule": 0,
+                "last": None}
 
 
 # ------------------------------------------------------------ shape buckets --
@@ -112,6 +126,41 @@ def cache_key(kernel: str, n: int, l: int, platform: Optional[str] = None) -> st
     return f"{kernel}|N{nb}xL{lb}|{plat}|jax{jax.__version__}"
 
 
+def sort_lanes(l: int) -> int:
+    """Lanes of the in-kernel sort: 2 breakpoints a padded lane, to pow2."""
+    return _next_pow2(2 * lane_pad(l))
+
+
+def vmem_bytes(row_block: int, l: int) -> int:
+    """Scoped VMEM the fused sortscan step takes at this row block over
+    ``l`` lanes: the double-buffered operand blocks and the sort's
+    temporaries grow with row block x sort lanes, plus a part that grows
+    with the sort lanes squared. An upper bound on what the v5e compiler
+    reported at every point measured (PERF.md); the projection kernels
+    hold fewer operands, so it bounds them too."""
+    p = sort_lanes(l)
+    return (VMEM_BYTES_PER_ROW_LANE * row_block * p
+            + p * p // VMEM_LANE_SQ_DIVISOR)
+
+
+def shape_rule(n: int, l: int) -> KernelConfig:
+    """The tiling dispatch runs when no tuned entry exists: the widest row
+    block that stays within the row bucket, fits ``VMEM_BUDGET``, keeps
+    the sort's tile within ``SORT_TILE_MAX``, and leaves at least
+    ``MIN_GRID_STEPS`` grid steps for the pipeline to overlap each block's
+    copy with the previous block's sort; the smallest block when none
+    does. Deterministic in (n, l), counted as ``rule``."""
+    nb, _ = shape_bucket(n, l)
+    fits = [rb for rb in ROW_BLOCKS
+            if rb <= nb and vmem_bytes(rb, l) <= VMEM_BUDGET
+            and rb * sort_lanes(l) <= SORT_TILE_MAX
+            and -(-n // rb) >= MIN_GRID_STEPS]
+    cfg = KernelConfig(max(fits, default=ROW_BLOCKS[0]))
+    _stats["rule"] += 1
+    _stats["last"] = (cfg.row_block, n, lane_pad(l))
+    return cfg
+
+
 # ---------------------------------------------------------- candidate space --
 def candidates(
     kernel: str,
@@ -123,8 +172,8 @@ def candidates(
 
     Row blocks beyond the padded row count only add padding, so they are
     capped at the row bucket; sortscan candidates additionally respect the
-    VMEM budget (the in-kernel sort holds ~6 row-block x 2*lanes f32
-    buffers). The bisect method enumerates its iteration count too.
+    VMEM budget (``vmem_bytes``). The bisect method enumerates its
+    iteration count too.
     """
     nb, lb = shape_bucket(n, l)
     out: list[KernelConfig] = []
@@ -135,8 +184,7 @@ def candidates(
             if rb > nb:
                 continue
             if method == "sortscan":
-                working = 6 * rb * (2 * _next_pow2(2 * lb)) * 4
-                if working > VMEM_BUDGET:
+                if vmem_bytes(rb, lb) > VMEM_BUDGET:
                     continue
                 out.append(KernelConfig(rb, "sortscan", 0))
             else:
@@ -151,8 +199,8 @@ def candidates(
 # ------------------------------------------------------------ on-disk table --
 def cache_path() -> Optional[str]:
     """The on-disk table under ``$REPRO_AUTOTUNE_CACHE``, or None when the
-    variable is unset: dispatch then runs the committed default tiling and
-    the compiled program depends on no file outside the checkout."""
+    variable is unset: dispatch then runs ``shape_rule``'s tiling and the
+    compiled program depends on no file outside the checkout."""
     env = os.environ.get(_CACHE_ENV)
     return os.path.join(env, "autotune.json") if env else None
 
@@ -165,10 +213,13 @@ def reset_cache() -> None:
 
 
 def reset_stats() -> None:
-    _stats.update(hits=0, misses=0, measurements=0)
+    _stats.update(hits=0, misses=0, measurements=0, rule=0, last=None)
 
 
 def cache_stats() -> dict:
+    """Counters since the last reset: table ``hits`` and ``misses``,
+    ``measurements`` taken, resolutions the shape ``rule`` answered, and
+    the ``last`` (row_block, rows, padded lanes) resolved, or None."""
     return dict(_stats)
 
 
@@ -219,7 +270,7 @@ def lookup(kernel: str, n: int, l: int) -> Optional[KernelConfig]:
 
 
 def resolve(kernel: str, n: int, l: int) -> KernelConfig:
-    """Dispatch-time tiling resolution: cached winner or the default.
+    """Dispatch-time tiling resolution: cached winner or ``shape_rule``.
 
     Never measures and never touches devices — safe inside jit tracing,
     where ``kernels.ops`` calls it on static shapes.
@@ -227,8 +278,9 @@ def resolve(kernel: str, n: int, l: int) -> KernelConfig:
     cfg = lookup(kernel, n, l)
     if cfg is None:
         _stats["misses"] += 1
-        return DEFAULT_CONFIG
+        return shape_rule(n, l)
     _stats["hits"] += 1
+    _stats["last"] = (cfg.row_block, n, lane_pad(l))
     return cfg
 
 

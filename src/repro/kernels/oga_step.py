@@ -114,8 +114,9 @@ def oga_step_fused(
     chunk).
 
     y, a, mask, x, kstar: (N, L). scal: (N, NUM_SCAL) per ``SCAL_COLUMNS``.
-    method/row_block/iters are the autotuned knobs (kernels.autotune
-    defaults when None; ``iters`` applies to method="bisect" only).
+    method/row_block/iters are the autotuned knobs (when None: sortscan,
+    ``autotune.shape_rule``'s row block, the default iteration count;
+    ``iters`` applies to method="bisect" only).
     Returns y(t+1) (N, L).
     """
     meth = method or autotune.DEFAULT_PROJ_METHOD
@@ -123,14 +124,14 @@ def oga_step_fused(
         raise ValueError(
             f"method must be in {autotune.PROJ_METHODS}, got {meth!r}"
         )
-    rb = row_block or autotune.DEFAULT_ROW_BLOCK
+    N, L = y.shape
+    rb = row_block or autotune.shape_rule(N, L).row_block
     it = iters or autotune.DEFAULT_BISECT_ITERS
     if scal.shape[1] > _SCAL_LANES:
         raise ValueError(
             f"scal has {scal.shape[1]} columns; the kernel packs them into "
             f"one {_SCAL_LANES}-lane block (layout {SCAL_COLUMNS})"
         )
-    N, L = y.shape
     pad_n = (-N) % rb
     pad_l = (-L) % autotune.LANE_FLOOR
     pad2 = lambda t: jnp.pad(t, ((0, pad_n), (0, pad_l)))

@@ -126,12 +126,12 @@ def _dispatch_fused(y_rows, a_rows, mask_rows, x_rows, kstar_rows, scal,
 
     ``tiling`` (an ``autotune.KernelConfig``) pins the Pallas tiling; when
     None it resolves from the autotune cache on the static packed shape —
-    winner if warmed, ``autotune.DEFAULT_CONFIG`` (the PR 4 hand-picked
-    tiling) on a miss. Production dispatch is value-deterministic: only
-    the exact sortscan method runs here regardless of what the cache
-    holds (a bisect entry contributes its row_block only — bisect output
-    depends on its iteration count, and cache state must never change
-    values, only speed). Explicit bisect A/B goes through
+    winner if warmed, ``autotune.shape_rule``'s row block on a miss.
+    Production dispatch is value-deterministic: only the exact sortscan
+    method runs here regardless of what the cache holds (a bisect entry
+    contributes its row_block only — bisect output depends on its
+    iteration count, and cache state must never change values, only
+    speed). Explicit bisect A/B goes through
     ``ops.oga_step_fused(tiling=...)``.
     """
     use = _on_tpu() if use_pallas is None else use_pallas

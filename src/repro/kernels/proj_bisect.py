@@ -33,9 +33,8 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import autotune
 
-# Back-compat aliases: the numbers themselves live in kernels.autotune (the
-# hardcoded-tiling lint rule keeps them there).
-ROW_BLOCK = autotune.DEFAULT_ROW_BLOCK
+# Back-compat alias: the number itself lives in kernels.autotune (the
+# hardcoded-tiling lint rule keeps it there).
 ITERS = autotune.DEFAULT_BISECT_ITERS
 NEG = -1e30
 
@@ -93,12 +92,13 @@ def proj_bisect(
 
     a, mask: (N, L); c: (N,). Rows are independent — the paper's per-(r,k)
     parallelism maps to the Pallas grid. ``row_block``/``iters`` are the
-    autotuned knobs (kernels.autotune defaults when None).
+    autotuned knobs (``autotune.shape_rule`` and the default iteration
+    count when None).
     """
-    rb = row_block or autotune.DEFAULT_ROW_BLOCK
+    N, L = z.shape
+    rb = row_block or autotune.shape_rule(N, L).row_block
     it = iters or autotune.DEFAULT_BISECT_ITERS
     lanes = autotune.LANE_FLOOR
-    N, L = z.shape
     pad_n = (-N) % rb
     pad_l = (-L) % lanes  # TPU lane alignment
     zp = jnp.pad(z, ((0, pad_n), (0, pad_l)))

@@ -45,13 +45,6 @@ from repro.kernels import autotune
 NEG = -1e30
 
 
-def _next_pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
-
-
 def _roll(x, shift: int):
     """Lane i of the result holds lane i - shift of x (mod P), as jnp.roll."""
     return pltpu.roll(x, shift % x.shape[-1], 1)
@@ -104,7 +97,7 @@ def _sortscan_water_level(z, a, m, c):
     binding) and is 0 elsewhere. Drop-in for proj_bisect._water_level.
     """
     rb, lp = z.shape
-    p = _next_pow2(2 * lp)
+    p = autotune.sort_lanes(lp)
 
     box = jnp.clip(z, 0.0, a) * m
     s_box = jnp.sum(box, axis=1, keepdims=True)
@@ -158,12 +151,12 @@ def proj_sortscan(z, a, mask, c, *, row_block=None, interpret: bool = False):
     sum(y * mask) <= c} — the sortscan sweep run on-device.
 
     a, mask: (N, L); c: (N,). ``row_block`` is the autotuned grid tile
-    (``autotune.DEFAULT_ROW_BLOCK`` when None); rows are independent, so
-    the tile only sets the grid shape, never the values.
+    (``autotune.shape_rule`` when None); rows are independent, so the tile
+    only sets the grid shape, never the values.
     """
-    rb = row_block or autotune.DEFAULT_ROW_BLOCK
-    lanes = autotune.LANE_FLOOR
     N, L = z.shape
+    rb = row_block or autotune.shape_rule(N, L).row_block
+    lanes = autotune.LANE_FLOOR
     pad_n = (-N) % rb
     pad_l = (-L) % lanes
     zp = jnp.pad(z, ((0, pad_n), (0, pad_l)))

@@ -119,7 +119,7 @@ def test_proj_sortscan_parity_every_autotuned_tile(row_block):
     want = ref.proj_rows_exact_np(z, a, mask, c)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-6)
     base = proj_sortscan(
-        z, a, mask, c, row_block=autotune.DEFAULT_ROW_BLOCK, interpret=True
+        z, a, mask, c, row_block=autotune.ROW_BLOCKS[0], interpret=True
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 

@@ -392,7 +392,7 @@ TILING_GOOD = """
 
     from repro.kernels import autotune
 
-    ROW_BLOCK = autotune.DEFAULT_ROW_BLOCK   # reference, not a literal
+    ROW_BLOCK = autotune.SUBLANE_FLOOR       # reference, not a literal
     MULTICLASS_ITERS = 24                    # a solver knob, not a tile
 
     def call(kernel, zp, rb, Lp):

@@ -22,8 +22,14 @@ from repro.kernels import autotune, oga_step, proj_bisect, sortscan
 
 # Tab. 2 deployment (R=128, K=6) over a 64-point sweep chunk: G*R*K rows
 TAB2_ROWS, TAB2_L = 64 * 128 * 6, 10
+# Fig. 5 deployment (R=1024, K=6), 100 job types: R*K rows
+FIG5_ROWS, FIG5_L = 1024 * 6, 100
 WIDE_ROWS, WIDE_L = 4096, 200
-ROW_BLOCK = autotune.DEFAULT_ROW_BLOCK  # 8, what dispatch runs
+
+
+def _row_block(kernel, n, l):
+    """The row block dispatch runs at this packed shape."""
+    return autotune.resolve(kernel, n, l).row_block
 
 
 @pytest.fixture(scope="module")
@@ -46,21 +52,25 @@ def _assert_mosaic(fn, args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("method", ["sortscan", "bisect"])
-def test_oga_step_fused_compiles_for_v5e(one_chip, method):
-    args = _shapes(one_chip, TAB2_ROWS, *[TAB2_L] * 5, oga_step.NUM_SCAL)
+@pytest.mark.parametrize("method,rows,lanes", [
+    ("sortscan", TAB2_ROWS, TAB2_L),
+    ("bisect", TAB2_ROWS, TAB2_L),
+    ("sortscan", FIG5_ROWS, FIG5_L),
+], ids=["sortscan", "bisect", "sortscan-fig5"])
+def test_oga_step_fused_compiles_for_v5e(one_chip, method, rows, lanes):
+    """At the tile dispatch resolves: a VMEM overrun there fails here."""
+    args = _shapes(one_chip, rows, *[lanes] * 5, oga_step.NUM_SCAL)
+    rb = _row_block("oga_step", rows, lanes)
     _assert_mosaic(
-        lambda *o: oga_step.oga_step_fused(
-            *o, method=method, row_block=ROW_BLOCK),
+        lambda *o: oga_step.oga_step_fused(*o, method=method, row_block=rb),
         args,
     )
 
 
 def test_proj_sortscan_wide_compiles_for_v5e(one_chip):
     args = _shapes(one_chip, WIDE_ROWS, WIDE_L, WIDE_L, WIDE_L, 0)
-    _assert_mosaic(
-        lambda *o: sortscan.proj_sortscan(*o, row_block=ROW_BLOCK), args
-    )
+    rb = _row_block("proj", WIDE_ROWS, WIDE_L)
+    _assert_mosaic(lambda *o: sortscan.proj_sortscan(*o, row_block=rb), args)
 
 
 # each kernel by the name its pallas_call gives it: its function, unjitted,
@@ -78,11 +88,13 @@ def test_kernel_op_keeps_its_name_under_any_caller(one_chip, kernel):
     """The TPU op of a kernel is named by its pallas_call's ``name``, not
     by the function that calls it: the name a trace reader looks for."""
     fn, widths = KERNELS[kernel]
+    rows = 768 if kernel == "oga_step_fused" else WIDE_ROWS
+    rb = _row_block("oga_step" if kernel == "oga_step_fused" else "proj",
+                    rows, widths[0])
 
     def renamed_caller(*o):
-        return fn.__wrapped__(*o, row_block=ROW_BLOCK)
+        return fn.__wrapped__(*o, row_block=rb)
 
-    rows = 768 if kernel == "oga_step_fused" else WIDE_ROWS
     text = jax.jit(renamed_caller).lower(
         *_shapes(one_chip, rows, *widths)).compile().as_text()
     ops = re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"

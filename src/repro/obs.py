@@ -23,11 +23,21 @@ NAMES = {
     # device scopes: chipbench/scopes.py reads each as a share of busy time
     "reward": "reward.total_reward, the reward every algorithm evaluates "
               "each slot: reward_busy_share",
-    "heuristic.<name>": "one heuristic's allocation step in baselines.run, "
-                        "its reward left out: heuristic_busy_share.<name>",
-    "oga.update": "OGASched's update in ogasched.oga_step and run_batch "
-                  "(packing, k*, the fused kernel, unpacking): "
-                  "oga_update_busy_share",
+    "heuristic.<name>": "one heuristic's allocation step in baselines.run "
+                        "and in the lifecycle step, its reward left out: "
+                        "heuristic_busy_share.<name>",
+    "oga.update": "OGASched's update in ogasched.oga_step, run_batch and "
+                  "the lifecycle step (packing, k*, the fused kernel, "
+                  "unpacking): oga_update_busy_share",
+    "lifecycle.evict": "the lifecycle step's fault multiplier and eviction "
+                       "(lifecycle._evict): scopes.py, by name",
+    "lifecycle.queue": "the lifecycle step's enqueue, admission and queue "
+                       "shifts: scopes.py, by name",
+    "lifecycle.allocate": "the lifecycle step's allocation: residual "
+                          "capacity, the heuristic's proposal, the exact "
+                          "projection, service rates: scopes.py, by name",
+    "lifecycle.service": "the lifecycle step's drain and departures: "
+                         "scopes.py, by name",
     # host spans: chipbench/scopes.py labels idle gaps by them and sums them
     "run_all.synthesis": "simulator.run_all's trace synthesis",
     "run_all.<algorithm>": "one algorithm's dispatch and wait in "
@@ -40,7 +50,8 @@ NAMES = {
     "sweep.synthesis": "the prefetch worker's build_batch of one chunk "
                        "(synthesis and upload), on the worker's thread",
     "sweep.summarize": "sweep.summarize's copy of a chunk's rewards to the "
-                       "host and their reduction there",
+                       "host and their reduction there; in lifecycle mode, "
+                       "summarize_lifecycle's reduction and copy",
     "online.dispatch": "JobManager.step's OGA step, dispatched op by op",
     "online.to_host": "JobManager.step's wait for y and its copy to the host",
     "online.grants": "JobManager.step's rounding of y to grants",

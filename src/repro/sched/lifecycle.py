@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import baselines, graph, projection, reward
 from repro.core.graph import ClusterSpec
 from repro.kernels import ops
@@ -331,118 +332,135 @@ def _step(
         evict = jnp.zeros((L,), bool)
         wasted_t = jnp.zeros((), dtype)
     else:
-        c_t = spec.c * f_t[None, :]
+        with obs.scope("lifecycle.evict"):
+            c_t = spec.c * f_t[None, :]
+            if algorithm in baselines.SIZE_AWARE:
+                evict = jnp.zeros((L,), bool)
+                wasted_t = jnp.zeros((), dtype)
+            else:
+                state, evict, wasted_t = _evict(
+                    spec, state, c_t, t, fault_policy, queue_depth
+                )
+
+    with obs.scope("lifecycle.queue"):
+        # -- enqueue arrivals (x is an indicator: <=1 job/port/slot) --
+        arrive = x_t > 0
+        can_q = state.q_len < queue_depth
+        push = arrive & can_q
+        pushf = push.astype(dtype)
+        tail = jax.nn.one_hot(state.q_len, queue_depth, dtype=dtype)  # (L, Q)
+        q_work = state.q_work + tail * (w_t * pushf)[:, None]
+        q_arr = state.q_arr + (tail * pushf[:, None]).astype(jnp.int32) * t
+        # arrivals are ready immediately (backoff gates only re-queued jobs)
+        # and start with a zero retry count, so q_retry is untouched by a push
+        q_ready = state.q_ready + (tail * pushf[:, None]).astype(jnp.int32) * t
+        q_retry = state.q_retry
+        q_len = state.q_len + push.astype(jnp.int32)
+        dropped = state.dropped + jnp.sum(arrive & ~can_q).astype(jnp.int32)
+
+        # -- admit the queue head wherever the port is idle (and, under
+        # faults, the head's backoff window has passed — the FIFO head
+        # gates the queue) --
+        idle = state.remaining <= 0
+        admit = idle & (q_len > 0)
+        if f_t is not None:
+            admit = admit & (q_ready[:, 0] <= t)
+        new_work = jnp.maximum(q_work[:, 0], WORK_FLOOR)
+        new_arr = q_arr[:, 0]
+        new_retry = q_retry[:, 0]
+        shift_w = jnp.concatenate(
+            [q_work[:, 1:], jnp.zeros((L, 1), dtype)], 1
+        )
+        shift_a = jnp.concatenate(
+            [q_arr[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1
+        )
+        shift_r = jnp.concatenate(
+            [q_ready[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1
+        )
+        shift_n = jnp.concatenate(
+            [q_retry[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1
+        )
+        q_work = jnp.where(admit[:, None], shift_w, q_work)
+        q_arr = jnp.where(admit[:, None], shift_a, q_arr)
+        q_ready = jnp.where(admit[:, None], shift_r, q_ready)
+        q_retry = jnp.where(admit[:, None], shift_n, q_retry)
+        q_len = q_len - admit.astype(jnp.int32)
+        admit_f = admit.astype(dtype)
+
+    with obs.scope("lifecycle.allocate"):
+        # -- allocate --
         if algorithm in baselines.SIZE_AWARE:
-            evict = jnp.zeros((L,), bool)
-            wasted_t = jnp.zeros((), dtype)
-        else:
-            state, evict, wasted_t = _evict(
-                spec, state, c_t, t, fault_policy, queue_depth
+            # Size-aware mode is PREEMPTIVE: heSRPT's optimality proof
+            # assumes the allocation is rebalanced whenever the active set
+            # changes (arXiv:1903.09346 §3), so each slot the policy
+            # re-divides the FULL surviving capacity across every active job
+            # — this slot's admissions plus all in-service jobs, whose
+            # residual works (state.remaining) are the sizes it ranks on.
+            # ``held`` is replaced wholesale; feasibility vs c_t is the
+            # policy's own water-fill invariant, so no residual-capacity
+            # netting is needed.
+            sizes = jnp.where(admit, new_work, state.remaining)
+            active_f = (sizes > 0).astype(dtype)
+            spec_t = (
+                spec if c_t is None else dataclasses.replace(spec, c=c_t)
             )
-
-    # -- enqueue arrivals (x is treated as an indicator: <=1 job/port/slot) --
-    arrive = x_t > 0
-    can_q = state.q_len < queue_depth
-    push = arrive & can_q
-    pushf = push.astype(dtype)
-    tail = jax.nn.one_hot(state.q_len, queue_depth, dtype=dtype)  # (L, Q)
-    q_work = state.q_work + tail * (w_t * pushf)[:, None]
-    q_arr = state.q_arr + (tail * pushf[:, None]).astype(jnp.int32) * t
-    # arrivals are ready immediately (backoff gates only re-queued jobs)
-    # and start with a zero retry count, so q_retry is untouched by a push
-    q_ready = state.q_ready + (tail * pushf[:, None]).astype(jnp.int32) * t
-    q_retry = state.q_retry
-    q_len = state.q_len + push.astype(jnp.int32)
-    dropped = state.dropped + jnp.sum(arrive & ~can_q).astype(jnp.int32)
-
-    # -- admit the queue head wherever the port is idle (and, under faults,
-    # the head's backoff window has passed — the FIFO head gates the queue) --
-    idle = state.remaining <= 0
-    admit = idle & (q_len > 0)
-    if f_t is not None:
-        admit = admit & (q_ready[:, 0] <= t)
-    new_work = jnp.maximum(q_work[:, 0], WORK_FLOOR)
-    new_arr = q_arr[:, 0]
-    new_retry = q_retry[:, 0]
-    shift_w = jnp.concatenate([q_work[:, 1:], jnp.zeros((L, 1), dtype)], 1)
-    shift_a = jnp.concatenate([q_arr[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1)
-    shift_r = jnp.concatenate(
-        [q_ready[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1
-    )
-    shift_n = jnp.concatenate(
-        [q_retry[:, 1:], jnp.zeros((L, 1), jnp.int32)], 1
-    )
-    q_work = jnp.where(admit[:, None], shift_w, q_work)
-    q_arr = jnp.where(admit[:, None], shift_a, q_arr)
-    q_ready = jnp.where(admit[:, None], shift_r, q_ready)
-    q_retry = jnp.where(admit[:, None], shift_n, q_retry)
-    q_len = q_len - admit.astype(jnp.int32)
-    admit_f = admit.astype(dtype)
-
-    # -- allocate --
-    if algorithm in baselines.SIZE_AWARE:
-        # Size-aware mode is PREEMPTIVE: heSRPT's optimality proof assumes
-        # the allocation is rebalanced whenever the active set changes
-        # (arXiv:1903.09346 §3), so each slot the policy re-divides the FULL
-        # surviving capacity across every active job — this slot's
-        # admissions plus all in-service jobs, whose residual works
-        # (state.remaining) are the sizes it ranks on. ``held`` is replaced
-        # wholesale; feasibility vs c_t is the policy's own water-fill
-        # invariant, so no residual-capacity netting is needed.
-        sizes = jnp.where(admit, new_work, state.remaining)
-        active_f = (sizes > 0).astype(dtype)
-        spec_t = (
-            spec if c_t is None else dataclasses.replace(spec, c=c_t)
-        )
-        held = baselines.step_fn(algorithm)(
-            spec_t, active_f, step_w, sizes=sizes
-        )
-        # admission reward on the admitted jobs' share, as in the held path
-        reward_t = reward.total_reward(
-            spec, admit_f, held * admit_f[:, None, None]
-        )
-        svc_rate = reward.service_rates(spec, held)
-    else:
-        # Heuristics and OGA hold allocations for a job's whole tenure:
-        # allocate the admitted jobs against the *surviving residual*
-        # capacity (nominal capacity when no fault stream runs).
-        c_res = graph.residual_capacity(spec, state.held, c_t)
-        if algorithm == "ogasched":
-            y_prop = state.y
-        else:
-            y_prop = baselines.step_fn(algorithm)(
-                graph.residual_spec(spec, state.held, c_t), admit_f, step_w
+            with obs.scope(f"heuristic.{algorithm}"):
+                held = baselines.step_fn(algorithm)(
+                    spec_t, active_f, step_w, sizes=sizes
+                )
+            # admission reward on the admitted jobs' share, as in the held
+            # path
+            reward_t = reward.total_reward(
+                spec, admit_f, held * admit_f[:, None, None]
             )
-        # exact one-sort projection (core.projection): the per-slot
-        # allocation used to be a second 64-pass bisection inside the scan.
-        alloc = projection.project_sorted(
-            y_prop * admit_f[:, None, None], spec.a, c_res, spec.mask
-        )
-        # a held allocation is fixed for the job's tenure, and so is its
-        # service rate: compute it once, at admission, and carry it
-        rate_alloc = reward.service_rates(spec, alloc)
-        reward_t = jnp.sum(admit_f * rate_alloc)
-        held = jnp.where(admit[:, None, None], alloc, state.held)
-        svc_rate = jnp.where(admit, rate_alloc, state.svc_rate)
-    remaining = jnp.where(admit, new_work, state.remaining)
-    svc_arr = jnp.where(admit, new_arr, state.svc_arr)
-    svc_start = jnp.where(admit, t, state.svc_start)
-    svc_work = jnp.where(admit, new_work, state.svc_work)
-    svc_retry = jnp.where(admit, new_retry, state.svc_retry)
-    used = jnp.sum(held * spec.mask[:, :, None], axis=0)  # (R, K) slot peak
+            svc_rate = reward.service_rates(spec, held)
+        else:
+            # Heuristics and OGA hold allocations for a job's whole tenure:
+            # allocate the admitted jobs against the *surviving residual*
+            # capacity (nominal capacity when no fault stream runs).
+            c_res = graph.residual_capacity(spec, state.held, c_t)
+            if algorithm == "ogasched":
+                y_prop = state.y
+            else:
+                with obs.scope(f"heuristic.{algorithm}"):
+                    y_prop = baselines.step_fn(algorithm)(
+                        graph.residual_spec(spec, state.held, c_t), admit_f,
+                        step_w,
+                    )
+            # exact one-sort projection (core.projection): the per-slot
+            # allocation used to be a second 64-pass bisection inside the
+            # scan.
+            alloc = projection.project_sorted(
+                y_prop * admit_f[:, None, None], spec.a, c_res, spec.mask
+            )
+            # a held allocation is fixed for the job's tenure, and so is its
+            # service rate: compute it once, at admission, and carry it
+            rate_alloc = reward.service_rates(spec, alloc)
+            reward_t = jnp.sum(admit_f * rate_alloc)
+            held = jnp.where(admit[:, None, None], alloc, state.held)
+            svc_rate = jnp.where(admit, rate_alloc, state.svc_rate)
+        remaining = jnp.where(admit, new_work, state.remaining)
+        svc_arr = jnp.where(admit, new_arr, state.svc_arr)
+        svc_start = jnp.where(admit, t, state.svc_start)
+        svc_work = jnp.where(admit, new_work, state.svc_work)
+        svc_retry = jnp.where(admit, new_retry, state.svc_retry)
+        # (R, K) slot peak
+        used = jnp.sum(held * spec.mask[:, :, None], axis=0)
 
-    # -- service: drain work at the utility-derived rate of the held alloc --
-    in_svc = remaining > 0
-    in_svc_f = in_svc.astype(dtype)
-    rates = jnp.maximum(svc_rate, rate_floor)
-    rem2 = remaining - rates * in_svc_f
-    work_done = jnp.minimum(rates, remaining) * in_svc_f
-    depart = in_svc & (rem2 <= 0)
-    departf = depart.astype(dtype)
-    jct = (t - svc_arr + 1).astype(dtype) * departf
-    svc_slots = (t - svc_start + 1).astype(dtype) * departf
-    held = jnp.where(depart[:, None, None], 0.0, held)
-    remaining = jnp.where(depart, 0.0, jnp.maximum(rem2, 0.0))
+    with obs.scope("lifecycle.service"):
+        # -- service: drain work at the utility-derived rate of the held
+        # allocation --
+        in_svc = remaining > 0
+        in_svc_f = in_svc.astype(dtype)
+        rates = jnp.maximum(svc_rate, rate_floor)
+        rem2 = remaining - rates * in_svc_f
+        work_done = jnp.minimum(rates, remaining) * in_svc_f
+        depart = in_svc & (rem2 <= 0)
+        departf = depart.astype(dtype)
+        jct = (t - svc_arr + 1).astype(dtype) * departf
+        svc_slots = (t - svc_start + 1).astype(dtype) * departf
+        held = jnp.where(depart[:, None, None], 0.0, held)
+        remaining = jnp.where(depart, 0.0, jnp.maximum(rem2, 0.0))
 
     # -- policy update: OGA ascends on the raw arrival indicator, exactly as
     # in slot mode — the learner sees the same stream either way; lifecycle
@@ -450,9 +468,11 @@ def _step(
     # residual capacity). Queue/occupancy/fault state never leaks into
     # learning: the regret comparator is defined on the nominal polytope.
     if algorithm == "ogasched":
-        y_next = ops.oga_update_spec(
-            spec, state.y, x_t, state.eta, backend=backend, operands=operands,
-        )
+        with obs.scope("oga.update"):
+            y_next = ops.oga_update_spec(
+                spec, state.y, x_t, state.eta, backend=backend,
+                operands=operands,
+            )
     else:
         y_next = state.y
 

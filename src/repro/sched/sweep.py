@@ -1176,7 +1176,10 @@ def summarize_lifecycle(
     slowdown_mean, utilization, ...). One jitted reduction per algorithm
     (lifecycle.summarize_batch) — no per-row Python loop."""
     out: dict[str, np.ndarray] = {}
-    for name, tr in traces.items():
-        for metric, v in lifecycle.summarize_batch(tr, batch.spec).items():
-            out[f"{metric}/{name}"] = np.asarray(v)
+    with obs.span("sweep.summarize"):
+        for name, tr in traces.items():
+            for metric, v in lifecycle.summarize_batch(
+                tr, batch.spec
+            ).items():
+                out[f"{metric}/{name}"] = np.asarray(v)
     return out

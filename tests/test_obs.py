@@ -143,3 +143,71 @@ def test_online_step_spans_dispatch_copy_and_grants(tmp_path):
     spans = [n for n, _ in _traced(tmp_path, lambda: jm.step(x))]
     assert spans == ["repro.online.dispatch", "repro.online.to_host",
                      "repro.online.grants"]
+
+
+LIFECYCLE_SCOPES = {"repro.lifecycle.evict", "repro.lifecycle.queue",
+                    "repro.lifecycle.allocate", "repro.lifecycle.service"}
+
+
+def _lifecycle_lowered(name):
+    from repro.sched import lifecycle
+
+    _, specs = _specs()
+    return sweep._run_grid_lifecycle.lower(
+        specs, jnp.ones((G, T, L)), jnp.full((G, T, L), 3.0), jnp.ones(G),
+        jnp.ones(G), jnp.asarray(1e-3), jnp.full((G, T, K), 0.7), name,
+        "auto" if name == "ogasched" else "reference", 4,
+        lifecycle.FaultPolicy())
+
+
+@pytest.mark.parametrize("name", ("ogasched",) + baselines.BASELINES)
+def test_lifecycle_programs_carry_their_steps_the_update_and_heuristic(name):
+    found = _scopes(_lifecycle_lowered(name))
+    assert LIFECYCLE_SCOPES <= found
+    if name == "ogasched":
+        assert "repro.oga.update" in found
+        assert not {f"repro.heuristic.{n}" for n in baselines.BASELINES} \
+            & found
+    else:
+        assert f"repro.heuristic.{name}" in found
+        assert "repro.oga.update" not in found
+
+
+def test_scopes_are_metadata_only(monkeypatch):
+    """With every scope a no-op, the lifecycle and slot-mode programs lower
+    to the same operations: a scope changes names, not arithmetic."""
+    import contextlib
+
+    _, specs = _specs()
+    slot = lambda: baselines.run_batch.lower(specs, jnp.ones((G, T, L)),
+                                             "drf")
+    plain = lambda lowered: lowered.as_text(debug_info=False)
+    with_scopes = [plain(_lifecycle_lowered("ogasched")),
+                   plain(_lifecycle_lowered("spreading")), plain(slot())]
+    assert not LIFECYCLE_SCOPES & _scopes(slot())
+    jax.clear_caches()
+    monkeypatch.setattr(obs, "scope", lambda name: contextlib.nullcontext())
+    try:
+        without = [plain(_lifecycle_lowered("ogasched")),
+                   plain(_lifecycle_lowered("spreading")), plain(slot())]
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert with_scopes == without
+
+
+def test_a_lifecycle_stream_spans_its_summary(tmp_path):
+    points = sweep.make_grid(
+        trace.TraceConfig(T=T, L=L, R=R, K=K,
+                          faults=trace.FaultConfig(fail_rate=0.2)),
+        eta0s=(5.0,), seeds=(1, 2))
+
+    def stream():
+        for _, batch, out in sweep.run_grid_stream(
+                points, chunk_size=2, mode="lifecycle", prefetch=0):
+            sweep.summarize_lifecycle(out, batch)
+
+    stream()
+    spans = [n for n, _ in _traced(tmp_path, stream)]
+    assert spans.count("repro.sweep.summarize") == 1
+    assert spans.count("repro.sweep.dispatch") == 1

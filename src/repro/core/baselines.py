@@ -55,15 +55,13 @@ _BIG = 1e30
 def _rank_order(v: jax.Array) -> jax.Array:
     """Stable ascending argsort of a short vector, without the sort primitive.
 
-    The port-order sort feeds ``_budgeted_fill``'s fori_loop as a
-    loop-invariant operand, and on jax 0.4.37's shard_map XLA:CPU miscompiles
-    exactly that pattern — a sort computed from sharded operands outside a
-    while loop and gathered inside it returns corrupted values on some
-    devices (sweep.run_grid_sharded exposed it; keeping the sort alive as a
-    program output makes it vanish, a fusion bug). Ranking by pairwise
-    comparison sidesteps the sort HLO entirely; at L <= a few dozen ports the
-    O(L^2) compare-reduce is noise, and the result is bit-identical to
-    ``jnp.argsort`` (stable, ties broken by index).
+    DRF's port order, by which ``_budgeted_fill`` permutes its operands
+    before the port loop. On jax 0.4.37's shard_map XLA:CPU miscompiled a
+    sort computed from sharded operands outside a while loop and gathered
+    inside it (sweep.run_grid_sharded exposed it, a fusion bug). Ranking by
+    pairwise comparison sidesteps the sort HLO entirely; at L <= a few dozen
+    ports the O(L^2) compare-reduce is noise, and the result is
+    bit-identical to ``jnp.argsort`` (stable, ties broken by index).
     """
     L = v.shape[0]
     idx = jnp.arange(L)
@@ -93,47 +91,58 @@ def _budgeted_fill(
     spec: ClusterSpec,
     x: jax.Array,
     w: jax.Array,
-    port_order: jax.Array,
     node_score_sign: float,
+    port_order: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Sequential-over-ports placement. Each port visits its connected nodes
     in preference order taking min(a_l^k, rem_r^k) until its per-resource
-    budget w_l * a_l^k is exhausted (vectorised via sorted cumsum)."""
-    L, R, K = spec.L, spec.R, spec.K
+    budget w_l * a_l^k is exhausted.
+
+    Ports are visited in ``port_order`` (index order when None); a port
+    with x_l = 0 takes nothing, so visiting it changes nothing. The loop
+    reads and writes row i of operands permuted once outside it, so under
+    vmap no per-port access becomes a gather or scatter. The budget a node
+    finds spent is the sum of ``take`` over the nodes preferred to it, by a
+    pairwise precedence mask in node index order: the order of a stable
+    ``argsort(-pref)`` without sorting.
+    """
+    R = spec.R
     a, c, mask = spec.a, spec.c, spec.mask
+    if port_order is not None:
+        x, w, a, mask = x[port_order], w[port_order], a[port_order], mask[port_order]
+    idx = jnp.arange(R)
+    earlier = idx[None, :] < idx[:, None]  # (R, R): s < r
 
     def port_body(i, carry):
         y, rem = carry
-        l = port_order[i]
-        active = x[l] * 1.0
+        a_l = jax.lax.dynamic_index_in_dim(a, i, keepdims=False)  # (K,)
+        m_l = jax.lax.dynamic_index_in_dim(mask, i, keepdims=False)  # (R,)
+        x_l = jax.lax.dynamic_index_in_dim(x, i, keepdims=False)
+        w_l = jax.lax.dynamic_index_in_dim(w, i, keepdims=False)
         # rem starts at c and only shrinks (take is clipped to rem), so
         # c - rem is the consumed amount, >= 0 by loop invariant even when
         # c is a fault-collapsed residual  # lint: disable=unvalidated-capacity-mask
         util = jnp.mean((c - rem) / jnp.maximum(c, 1e-9), axis=1)  # (R,)
         # preference: score desc; natural index order as tiebreak
-        pref = node_score_sign * util - 1e-6 * jnp.arange(R)
-        pref = jnp.where(mask[l] > 0, pref, -_BIG)
-        # Loop-VARYING sort: pref depends on the carried rem, so XLA cannot
-        # hoist it the way the PR 3 loop-invariant port-order sort was
-        # miscompiled; shard_map == vmap stays pinned bitwise over this path
-        # by tests/test_sweep_sharded.py, and a sort-free O(R^2) ranking is
-        # infeasible at dryrun scale (R=131072).
-        order = jnp.argsort(-pref)  # lint: disable=sort-in-loop
-        take = jnp.minimum(a[l][None, :], rem[order]) * mask[l][order][:, None]
-        cum = jnp.cumsum(take, axis=0)  # (R, K) cumulative if all taken
-        budget = w[l] * a[l]  # (K,)
-        allowed = jnp.clip(budget[None, :] - (cum - take), 0.0, take)
-        allowed = allowed * active
-        # invert the permutation without a second sort (argsort of a
-        # permutation == its inverse; the scatter is exact and cheaper)
-        inv = jnp.zeros_like(order).at[order].set(jnp.arange(R))
-        got = allowed[inv]  # back to node index order, (R, K)
-        y = y.at[l].add(got)
-        rem = rem - got
-        return (y, rem)
+        pref = node_score_sign * util - 1e-6 * idx
+        pref = jnp.where(m_l > 0, pref, -_BIG)
+        # prec[r, s]: node s comes before node r
+        prec = (pref[None, :] > pref[:, None]) | (
+            (pref[None, :] == pref[:, None]) & earlier
+        )
+        take = jnp.minimum(a_l[None, :], rem) * m_l[:, None]  # (R, K)
+        # (R, K) taken by the nodes before each node; HIGHEST keeps the TPU
+        # from summing take as bf16 (the 0/1 mask is exact either way)
+        before = jnp.dot(prec.astype(take.dtype), take,
+                         precision=jax.lax.Precision.HIGHEST)
+        allowed = jnp.clip(w_l * a_l[None, :] - before, 0.0, take) * x_l
+        y = jax.lax.dynamic_update_index_in_dim(y, allowed, i, 0)
+        return (y, rem - allowed)
 
-    y0 = jnp.zeros((L, R, K), a.dtype)
-    y, _ = jax.lax.fori_loop(0, L, port_body, (y0, c))
+    y0 = jnp.zeros((spec.L, R, spec.K), a.dtype)
+    y, _ = jax.lax.fori_loop(0, spec.L, port_body, (y0, c))
+    if port_order is not None:
+        y = jnp.zeros_like(y).at[port_order].set(y)
     return y
 
 
@@ -155,26 +164,19 @@ def drf_step(spec: ClusterSpec, x: jax.Array, w=None) -> jax.Array:
                        precision=jax.lax.Precision.HIGHEST)
     s = jnp.max(spec.a / jnp.maximum(cap_l, 1e-9), axis=1)  # (L,)
     s = jnp.where(x > 0, s, _BIG)  # arrived ports first
-    order = _rank_order(s)
-    return _budgeted_fill(spec, x, w, order, node_score_sign=0.0)
+    return _budgeted_fill(spec, x, w, 0.0, port_order=_rank_order(s))
 
 
 def binpacking_step(spec: ClusterSpec, x: jax.Array, w=None) -> jax.Array:
     """BINPACKING / MostAllocated: favour high-utilization instances."""
     w = _default_w(spec, "binpacking") if w is None else w
-    order = _rank_order(
-        jnp.where(x > 0, jnp.arange(spec.L, dtype=jnp.float32), _BIG)
-    )
-    return _budgeted_fill(spec, x, w, order, node_score_sign=+1.0)
+    return _budgeted_fill(spec, x, w, +1.0)
 
 
 def spreading_step(spec: ClusterSpec, x: jax.Array, w=None) -> jax.Array:
     """SPREADING / LeastAllocated: favour low-utilization instances."""
     w = _default_w(spec, "spreading") if w is None else w
-    order = _rank_order(
-        jnp.where(x > 0, jnp.arange(spec.L, dtype=jnp.float32), _BIG)
-    )
-    return _budgeted_fill(spec, x, w, order, node_score_sign=-1.0)
+    return _budgeted_fill(spec, x, w, -1.0)
 
 
 # ---------------------------------------------------------------------------

@@ -126,3 +126,95 @@ def test_drf_orders_by_dominant_share():
     got0, got1 = float(y[0, 0, 0]), float(y[1, 0, 0])
     assert got0 == pytest.approx(1.0, abs=1e-5)  # low share served first
     assert got1 <= 9.0 + 1e-4
+
+
+# -------------------------------------------- budgeted fill against a sort
+
+def _oracle_fill(spec, x, w, port_order, sign):
+    """The budgeted fill by sort and cumsum, port by port, in numpy."""
+    a, c, mask = (np.asarray(v, np.float32) for v in (spec.a, spec.c, spec.mask))
+    x, w = np.asarray(x, np.float32), np.asarray(w, np.float32)
+    L, R = mask.shape
+    y = np.zeros((L, R, a.shape[1]), np.float32)
+    rem = c.copy()
+    for l in port_order:
+        used = np.maximum(c - rem, np.float32(0.0))  # rem <= c: a no-op
+        util = np.mean(used / np.maximum(c, np.float32(1e-9)), axis=1)
+        pref = np.float32(sign) * util - np.float32(1e-6) * np.arange(R, dtype=np.float32)
+        pref = np.where(mask[l] > 0, pref, np.float32(-1e30))
+        order = np.argsort(-pref, kind="stable")
+        take = np.minimum(a[l], rem[order]) * mask[l][order][:, None]
+        cum = np.cumsum(take, axis=0)
+        allowed = np.clip(w[l] * a[l] - (cum - take), 0.0, take) * x[l]
+        y[l, order] = allowed
+        rem[order] -= allowed
+    return y
+
+
+def _arrived_first(x):
+    return np.argsort(np.where(np.asarray(x) > 0, 0, 1), kind="stable")
+
+
+def _oracle_step(name, spec, x):
+    w = baselines.default_parallelism(spec, name)
+    if name == "drf":
+        cap = np.asarray(spec.mask, np.float64) @ np.asarray(spec.c, np.float64)
+        s = np.max(np.asarray(spec.a) / np.maximum(cap, 1e-9), axis=1)
+        order = np.argsort(np.where(np.asarray(x) > 0, s, 1e30), kind="stable")
+        return _oracle_fill(spec, x, w, order, 0.0)
+    sign = 1.0 if name == "binpacking" else -1.0
+    return _oracle_fill(spec, x, w, _arrived_first(x), sign)
+
+
+# the Tab. 2 shape over 12 slots, the Fig. 5 shape over a few
+FILL_SHAPES = {
+    "tab2": (trace.TraceConfig(T=12, seed=3), range(12)),
+    "fig5": (trace.TraceConfig(T=4, L=100, R=1024, K=6, rho=0.95,
+                               contention=5.0, beta_range=(0.01, 0.015),
+                               seed=3), range(2)),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(FILL_SHAPES))
+def fill_case(request):
+    cfg, slots = FILL_SHAPES[request.param]
+    spec, arr = trace.make(cfg)
+    return spec, [arr[t] for t in slots]
+
+
+@pytest.mark.parametrize("name", ["drf", "binpacking", "spreading"])
+def test_budgeted_fill_matches_sort_oracle(fill_case, name):
+    """The precedence-mask fill gives the sort-and-cumsum fill's rewards,
+    stays feasible and leaves non-arrived ports empty."""
+    from repro.core import reward
+
+    spec, xs = fill_case
+    step = jax.jit(baselines.step_fn(name))
+    w = baselines.default_parallelism(spec, name)
+    got, want = [], []
+    for x in xs:
+        y = step(spec, x, w)
+        assert bool(graph.feasible(spec, y)), name
+        off = np.asarray(x) == 0
+        assert np.all(np.asarray(y)[off] == 0.0), name
+        got.append(float(reward.total_reward(spec, x, y)))
+        want.append(float(reward.total_reward(
+            spec, x, jnp.asarray(_oracle_step(name, spec, x)))))
+    got, want = np.asarray(got), np.asarray(want)
+    gap = np.max(np.abs(got - want)) / np.mean(np.abs(want))
+    assert gap <= 1e-6, (name, gap)
+
+
+@pytest.mark.parametrize("name,sign", [("binpacking", 1.0), ("spreading", -1.0)])
+def test_index_order_equals_arrived_first_order(fill_case, name, sign):
+    """A port that did not arrive takes nothing, so visiting every port in
+    index order gives the arrived-first order's decision bit for bit."""
+    spec, xs = fill_case
+    w = baselines.default_parallelism(spec, name)
+    fill = jax.jit(baselines._budgeted_fill, static_argnums=3)
+    for x in xs:
+        order = jnp.asarray(_arrived_first(x), jnp.int32)
+        np.testing.assert_array_equal(
+            np.asarray(fill(spec, x, w, sign)),
+            np.asarray(fill(spec, x, w, sign, order)),
+        )

@@ -10,6 +10,7 @@ The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU compiler's library, and every
 test worker imports this file.
 """
+import collections
 import os
 import re
 
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import baselines, graph
 from repro.kernels import autotune, oga_step, proj_bisect, sortscan
 
 # Tab. 2 deployment (R=128, K=6) over a 64-point sweep chunk: G*R*K rows
@@ -100,3 +102,85 @@ def test_kernel_op_keeps_its_name_under_any_caller(one_chip, kernel):
     ops = re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"
                      r'custom_call_target="tpu_custom_call"', text)
     assert ops and all(re.fullmatch(rf"{kernel}(\.\d+)?", op) for op in ops)
+
+
+# ---------------------------------------- the budgeted heuristics' port loop
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) .*\{$")
+_OPCODE = re.compile(r"^\s*(?:ROOT )?%?[\w.-]+ = .*?\b([a-z][\w-]*)\(")
+_CALLED = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.-]+)")
+
+
+def _computations(text):
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if cur is None and m:
+            cur = m.group(1)
+            comps[cur] = []
+        elif line.strip() == "}":
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps
+
+
+def _opcodes(comps, root):
+    """Opcodes of ``root`` and every computation it calls, fusions too."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += _CALLED.findall(line)
+    return collections.Counter(
+        m.group(1) for n in seen for m in map(_OPCODE.match, comps[n]) if m)
+
+
+def _innermost_loop_bodies(text):
+    comps = _computations(text)
+    bodies = {b for lines in comps.values() for line in lines
+              for b in re.findall(r"body=%?([\w.-]+)", line)}
+    ops = {b: _opcodes(comps, b) for b in bodies}
+    return {b: o for b, o in ops.items() if not o["while"]}
+
+
+def _spec_shapes(sharding, L, R, K, G=None):
+    lead = () if G is None else (G,)
+
+    def f(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=sharding)
+
+    return graph.ClusterSpec(mask=f(L, R), a=f(L, K), c=f(R, K),
+                             alpha=f(R, K), beta=f(K),
+                             kinds=f(K, dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("name", ["drf", "binpacking"])
+@pytest.mark.parametrize("shape", ["fig5", "tab2"])
+def test_port_loop_moves_no_data_for_v5e(one_chip, name, shape):
+    """The port loop's body, fused computations included, holds no sort,
+    gather, scatter or cumsum: a sort over R nodes and per-element
+    permutations there ran close to one index at a time on the chip."""
+    T = 500
+    if shape == "fig5":  # baselines.run on one deployment, as run_all does
+        fn = baselines.run
+        args = (_spec_shapes(one_chip, FIG5_L, 1024, 6),
+                jax.ShapeDtypeStruct((T, FIG5_L), jnp.float32,
+                                     sharding=one_chip))
+    else:  # a 64-point sweep chunk under vmap
+        fn = baselines.run_batch
+        args = (_spec_shapes(one_chip, TAB2_L, 128, 6, G=64),
+                jax.ShapeDtypeStruct((64, T, TAB2_L), jnp.float32,
+                                     sharding=one_chip))
+    text = jax.jit(lambda s, a: fn(s, a, name)).lower(*args).compile().as_text()
+    bodies = _innermost_loop_bodies(text)
+    assert bodies
+    for body, ops in bodies.items():
+        assert ops["dynamic-update-slice"], body
+        moved = {op: ops[op] for op in
+                 ("sort", "gather", "scatter", "reduce-window") if ops[op]}
+        assert not moved, (body, moved)
